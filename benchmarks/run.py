@@ -14,10 +14,13 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+
 from .common import save_json, scale   # noqa: E402
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale settings (needs real hardware)")

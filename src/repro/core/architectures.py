@@ -67,6 +67,7 @@ class FedRunResult:
                                    # run (None when dp= was off; inf when the
                                    # batch exceeds the smallest client, where
                                    # the subsampling estimate is undefined)
+    final_d_params: dict | None = None  # merged discriminator (fed/host runs)
 
 
 def _states_finite(states: GANState) -> bool:
@@ -387,7 +388,9 @@ def run_federated(client_data: list[np.ndarray], schema: list[ColumnSpec],
                         jax.tree.map(lambda x: x[0], states.g_params),
                         dt, bytes_round, retries=retries,
                         blocked=blocked if use_faulted else None,
-                        epsilon=epsilon)
+                        epsilon=epsilon,
+                        final_d_params=jax.tree.map(lambda x: x[0],
+                                                    states.d_params))
 
 
 def run_centralized(data: np.ndarray, schema: list[ColumnSpec], *,

@@ -14,22 +14,11 @@ part of the state is aggregated (params; optimizer moments stay local).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-try:                                    # jax >= 0.6 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The "don't verify replication" kwarg was renamed check_rep -> check_vma.
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
 
 from .aggregation import psum_weighted
 
@@ -141,11 +130,11 @@ def shard_map_federated_round(mesh, step_fn, state_specs,
 
     def wrapped(state, batches, weights):
         batch_in_specs = jax.tree.map(lambda _: P(client_axis), batches)
-        return _shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(state_specs, batch_in_specs, P(client_axis)),
             out_specs=(state_specs, P(client_axis)),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )(state, batches, weights)
 
     return wrapped

@@ -49,7 +49,6 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from ..core.aggregation import psum_weighted
-from ..core.fedavg import _CHECK_KW, _shard_map
 from ..gan.ctgan import CTGANConfig
 from ..synth import RoundEngine
 from ..tabular.encoders import SpanInfo
@@ -88,11 +87,11 @@ def shard_map_weighted_round(mesh, engine: RoundEngine, *,
             raise ValueError(
                 f"stacked client axis ({P_clients}) must equal the client "
                 f"mesh axis size ({axis_size} over {ca})")
-        return _shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(ca), P(ca), P(ca), P(ca)),
             out_specs=(P(ca), P(ca)),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )(states, tables, w, keys)
 
     return round_fn

@@ -62,6 +62,15 @@ def init_generator(key: jax.Array, cfg: CTGANConfig, cond_dim: int,
     return params
 
 
+def is_pre_bn_bias(path) -> bool:
+    """True for a generator leaf ``res*/fc/b`` (a ``tree_flatten_with_path``
+    key path): the bias right before a batch norm, which subtracts it
+    again, so its exact gradient is zero and what a backward pass computes
+    for it is rounding noise."""
+    keys = [getattr(k, "key", None) for k in path]
+    return keys[-2:] == ["fc", "b"] and str(keys[0]).startswith("res")
+
+
 def init_discriminator(key: jax.Array, cfg: CTGANConfig, cond_dim: int,
                        data_dim: int) -> dict:
     keys = jax.random.split(key, len(cfg.disc_hidden) + 1)

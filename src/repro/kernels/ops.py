@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels with jnp fallbacks.
 
-On CPU (this container) the kernels run in interpret mode; on TPU they
-lower to Mosaic.  ``use_pallas=False`` routes to the ref oracles so every
-call site can be flipped for A/B testing.
+``use_pallas=None`` auto-routes: on TPU every fused stage lowers to
+Mosaic; on CPU it runs the bit-identical jnp ref, and ``interpret=True``
+forces the kernel through the Pallas interpreter for kernel-path tests.
+``use_pallas=False`` routes to the ref oracles so every call site can be
+flipped for A/B testing.
 """
 from __future__ import annotations
 
@@ -42,6 +44,52 @@ _weighted_agg_edges_ref = jax.jit(jax.vmap(ref.weighted_agg_ref))
 # to prove the fused encode path issues ONE dispatch where the per-column
 # loop issues Q_cont.  Reset with ``DISPATCH_COUNTS.clear()``.
 DISPATCH_COUNTS: collections.Counter = collections.Counter()
+
+# Pallas-route calls that ran in the interpreter (per stage).  On the chip
+# this must stay empty: a kernel quietly interpreted there would run the
+# stage on the host while the run still reports the Pallas route.
+INTERPRET_COUNTS: collections.Counter = collections.Counter()
+
+# Row-tile cap for the TPU kernels (VMEM-sized VPU tiles).
+_MAX_ROW_TILE = 1024
+
+
+def _route(stage: str, use_pallas, interpret):
+    """Resolve one call's backend and count it in ``DISPATCH_COUNTS``.
+
+    Returns ``None`` for the jnp-ref route, else the ``interpret`` flag of
+    the Pallas route (the kernel on TPU, or whenever ``interpret`` is
+    requested explicitly; the ref on CPU, where interpret-mode emulation
+    is pure overhead)."""
+    if use_pallas is None:
+        use_pallas = _ON_TPU or interpret is not None
+    if not use_pallas:
+        DISPATCH_COUNTS[stage + "_ref"] += 1
+        return None
+    DISPATCH_COUNTS[stage] += 1
+    interp = (not _ON_TPU) if interpret is None else bool(interpret)
+    if interp:
+        INTERPRET_COUNTS[stage] += 1
+    return interp
+
+
+def row_tile(n: int) -> int:
+    """TPU row tile for ``n`` rows: the fewest blocks of at most 1024
+    rows, each a multiple of 8 (the sublane tile).  A 500-row batch runs
+    as one 504-row block and 40,000 rows as 40 blocks of 1000; the rows
+    padded past ``n`` stay under 8 per block."""
+    n = max(int(n), 1)
+    per_block = -(-n // -(-n // _MAX_ROW_TILE))  # ceil(n / blocks)
+    return -(-per_block // 8) * 8
+
+
+def _block_rows(n: int, interp: bool, block_n):
+    """Row tile of a call: ``block_n`` if given, else the whole table in
+    interpret mode (per-grid-cell emulation overhead dominates there) and
+    :func:`row_tile` on the chip."""
+    if block_n is not None:
+        return block_n
+    return max(int(n), 1) if interp else row_tile(n)
 
 
 @contextlib.contextmanager
@@ -99,13 +147,9 @@ def vgm_encode(x, params, key, *, use_pallas=None, interpret=None,
     K = params.means.shape[0]
     logw = kernel_log_weights(params)
     gumbel = jax.random.gumbel(key, (x.shape[0], K), jnp.float32)
-    if use_pallas is None:
-        use_pallas = _ON_TPU or interpret is not None
-    if not use_pallas:
-        DISPATCH_COUNTS["vgm_encode_ref"] += 1
+    interp = _route("vgm_encode", use_pallas, interpret)
+    if interp is None:
         return ref.vgm_encode_ref(x, params.means, params.stds, logw, gumbel)
-    DISPATCH_COUNTS["vgm_encode"] += 1
-    interp = (not _ON_TPU) if interpret is None else interpret
     return _vgm_encode(x, params.means, params.stds, logw, gumbel,
                        block_n=block_n, interpret=interp)
 
@@ -117,21 +161,17 @@ def vgm_encode_table(x_cols, means, stds, log_weights, gumbel, *,
     and pre-drawn gumbel (N, Q*Kmax); returns slots (N, Q*(1+Kmax)).
 
     ``use_pallas=None`` auto-routes like :func:`vgm_encode`.  ``block_n=None``
-    picks the row tile: 1024 on TPU (VMEM-sized VPU tiles); the whole table
-    in interpret mode, where per-grid-cell emulation overhead dominates and
+    picks the row tile: :func:`row_tile` on TPU; the whole table in
+    interpret mode, where per-grid-cell emulation overhead dominates and
     one row block per column is fastest."""
-    if use_pallas is None:
-        use_pallas = _ON_TPU or interpret is not None
-    if not use_pallas:
-        DISPATCH_COUNTS["vgm_encode_table_ref"] += 1
+    interp = _route("vgm_encode_table", use_pallas, interpret)
+    if interp is None:
         return ref.vgm_encode_table_ref(x_cols, means, stds, log_weights,
                                         gumbel)
-    DISPATCH_COUNTS["vgm_encode_table"] += 1
-    interp = (not _ON_TPU) if interpret is None else interpret
-    if block_n is None:
-        block_n = max(int(x_cols.shape[0]), 1) if interp else 1024
     return _vgm_encode_table(x_cols, means, stds, log_weights, gumbel,
-                             block_n=block_n, interpret=interp)
+                             block_n=_block_rows(x_cols.shape[0], interp,
+                                                 block_n),
+                             interpret=interp)
 
 
 def vgm_decode_table(slots, means, stds, *, use_pallas=None, interpret=None,
@@ -142,18 +182,13 @@ def vgm_decode_table(slots, means, stds, *, use_pallas=None, interpret=None,
     ``(Q, Kmax)`` params.  Returns raw columns (N, Q).
 
     ``use_pallas=None`` auto-routes like :func:`vgm_encode_table`, and
-    ``block_n=None`` picks the same row tile policy (1024 on TPU, the
-    whole table in interpret mode)."""
-    if use_pallas is None:
-        use_pallas = _ON_TPU or interpret is not None
-    if not use_pallas:
-        DISPATCH_COUNTS["vgm_decode_table_ref"] += 1
+    ``block_n=None`` picks the same row tile policy."""
+    interp = _route("vgm_decode_table", use_pallas, interpret)
+    if interp is None:
         return _vgm_decode_table_ref(slots, means, stds)
-    DISPATCH_COUNTS["vgm_decode_table"] += 1
-    interp = (not _ON_TPU) if interpret is None else interpret
-    if block_n is None:
-        block_n = max(int(slots.shape[0]), 1) if interp else 1024
-    return _vgm_decode_table(slots, means, stds, block_n=block_n,
+    return _vgm_decode_table(slots, means, stds,
+                             block_n=_block_rows(slots.shape[0], interp,
+                                                 block_n),
                              interpret=interp)
 
 
@@ -170,8 +205,7 @@ def segment_activations(logits, spans, key, tau, hard=False, *,
     and loop see identical randoms and agree bit-for-bit on values.
 
     ``use_pallas=None`` auto-routes like :func:`vgm_encode_table`, and
-    ``block_n=None`` picks the same row tile policy (1024 on TPU, the
-    whole batch in interpret mode)."""
+    ``block_n=None`` picks the same row tile policy."""
     layout = build_span_layout(tuple(spans))
     n = logits.shape[0]
     keys = jax.random.split(key, len(layout.spans))
@@ -188,19 +222,14 @@ def segment_activations(logits, spans, key, tau, hard=False, *,
                          jnp.take(logits.astype(jnp.float32),
                                   layout.pack_src, axis=1))
     tau, hard = float(tau), bool(hard)
-    if use_pallas is None:
-        use_pallas = _ON_TPU or interpret is not None
-    if not use_pallas:
-        DISPATCH_COUNTS["segment_activations_ref"] += 1
+    interp = _route("segment_activations", use_pallas, interpret)
+    if interp is None:
         out = segment_activations_packed(packed_x, packed_u, layout.kinds,
                                          tau, hard, False, False, 0)
     else:
-        DISPATCH_COUNTS["segment_activations"] += 1
-        interp = (not _ON_TPU) if interpret is None else interpret
-        if block_n is None:
-            block_n = max(int(n), 1) if interp else 1024
         out = segment_activations_packed(packed_x, packed_u, layout.kinds,
-                                         tau, hard, True, interp, block_n)
+                                         tau, hard, True, interp,
+                                         _block_rows(n, interp, block_n))
     return jnp.take(out, layout.unpack_src, axis=1)
 
 
@@ -222,13 +251,9 @@ def weighted_average_flat(stacked, weights, *, use_pallas=None,
     call counts toward the one-merge-dispatch-per-round contract the fed
     layer asserts (``weighted_agg`` / ``weighted_agg_ref`` in
     ``DISPATCH_COUNTS``)."""
-    if use_pallas is None:
-        use_pallas = _ON_TPU or interpret is not None
-    if not use_pallas:
-        DISPATCH_COUNTS["weighted_agg_ref"] += 1
+    interp = _route("weighted_agg", use_pallas, interpret)
+    if interp is None:
         return _weighted_agg_ref(stacked, weights)
-    DISPATCH_COUNTS["weighted_agg"] += 1
-    interp = (not _ON_TPU) if interpret is None else interpret
     return _weighted_agg(stacked, weights, block_d=block_d, interpret=interp)
 
 
@@ -243,13 +268,9 @@ def weighted_average_edges(stacked, weights, *, use_pallas=None,
     ``use_pallas=None`` auto-routes like :func:`weighted_average_flat`,
     and the call counts ONCE toward the one-merge-dispatch-per-tier
     contract (``weighted_agg`` / ``weighted_agg_ref``)."""
-    if use_pallas is None:
-        use_pallas = _ON_TPU or interpret is not None
-    if not use_pallas:
-        DISPATCH_COUNTS["weighted_agg_ref"] += 1
+    interp = _route("weighted_agg", use_pallas, interpret)
+    if interp is None:
         return _weighted_agg_edges_ref(stacked, weights)
-    DISPATCH_COUNTS["weighted_agg"] += 1
-    interp = (not _ON_TPU) if interpret is None else interpret
     return jax.vmap(
         lambda s, w: _weighted_agg(s, w, block_d=block_d,
                                    interpret=interp))(stacked, weights)

@@ -145,6 +145,10 @@ def segment_activations(packed_x: jnp.ndarray, packed_u: jnp.ndarray,
     Returns packed activations (N, S*Wmax): tanh rows hold tanh(x) in
     live lanes, softmax rows hold the Gumbel-softmax (ST one-hot when
     ``hard``) with exactly zero mass on padded lanes.
+
+    Inside, the kernel runs span-major — ``(S, N, Wmax)`` with the span
+    axis squeezed out of every block — so each block's last two dims are
+    (row tile, full span width) as Mosaic requires.
     """
     N = packed_x.shape[0]
     S, W = kinds.shape
@@ -155,19 +159,22 @@ def segment_activations(packed_x: jnp.ndarray, packed_u: jnp.ndarray,
                            constant_values=0.5)
     Np = N + pad_n
 
+    def span_major(a):                                    # (S, Np, W)
+        return a.reshape(Np, S, W).transpose(1, 0, 2)
+
+    def rows_block():
+        return pl.BlockSpec((None, block_n, W), lambda i, j: (j, i, 0))
+
     out = pl.pallas_call(
         functools.partial(_segment_act_kernel, tau=tau, hard=hard),
         grid=(Np // block_n, S),
-        in_specs=[
-            pl.BlockSpec((block_n, W), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, W), lambda i, j: (i, j)),
-            pl.BlockSpec((1, W), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, W), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Np, S * W), jnp.float32),
+        in_specs=[rows_block(), rows_block(),
+                  pl.BlockSpec((None, 1, W), lambda i, j: (j, 0, 0))],
+        out_specs=rows_block(),
+        out_shape=jax.ShapeDtypeStruct((S, Np, W), jnp.float32),
         interpret=interpret,
-    )(packed_x, packed_u, kinds)
-    return out[:N]
+    )(span_major(packed_x), span_major(packed_u), kinds[:, None, :])
+    return out.transpose(1, 0, 2).reshape(Np, S * W)[:N]
 
 
 def _packed_primal(packed_x, packed_u, kinds, tau, hard, use_pallas,

@@ -40,11 +40,19 @@ from jax.experimental import pallas as pl
 def _mode_denormalize(slots, means, stds):
     """Shared body: slots (bn, 1+K) = [alpha, beta...]; means/stds (1, K).
     Returns (bn,) raw values.  Matches tabular.vgm.decode_column op-for-op
-    (same clip / multiply order) so the fused path is bit-identical."""
+    (same clip / multiply order) so the fused path is bit-identical.
+
+    The beta argmax runs over the whole slot tile with the alpha lane
+    masked to a true ``-inf`` (below the ``NEG_INF`` padding sentinel, so
+    it never ties a padded lane) instead of slicing lanes ``1:`` — an
+    unaligned lane slice Mosaic would have to shift."""
     alpha = slots[:, 0]
-    beta = slots[:, 1:]
-    comp = jnp.argmax(beta, axis=1)                     # (bn,)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, beta.shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, slots.shape, 1)
+    winner = jnp.argmax(jnp.where(lane == 0, -jnp.inf, slots), axis=1)
+    # a row of all-(-inf) betas leaves lane 0 winning: mode 0, as the
+    # ref's argmax over the betas alone picks
+    comp = jnp.maximum(winner - 1, 0)
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, means.shape, 1)
               == comp[:, None]).astype(jnp.float32)
     mu = jnp.sum(onehot * means, axis=1)
     sd = jnp.sum(onehot * stds, axis=1)
@@ -72,6 +80,10 @@ def vgm_decode_table(slots: jnp.ndarray, means: jnp.ndarray,
 
     Returns x_cols (N, Q) raw continuous columns, bit-identical to running
     ``tabular.vgm.decode_column`` per column on the unpacked spans.
+
+    Inside, the kernel runs column-major like the encode kernel: slots
+    ``(Q, N, 1+Kmax)``, params ``(Q, 1, Kmax)``, output ``(Q, N, 1)``,
+    with the column axis squeezed out of every block.
     """
     N = slots.shape[0]
     Q, K = means.shape
@@ -80,17 +92,18 @@ def vgm_decode_table(slots: jnp.ndarray, means: jnp.ndarray,
     if pad_n:
         slots = jnp.pad(slots, ((0, pad_n), (0, 0)))
     Np = N + pad_n
+    slots_cm = slots.reshape(Np, Q, S).transpose(1, 0, 2)   # (Q, Np, S)
 
     out = pl.pallas_call(
         _vgm_decode_kernel,
         grid=(Np // block_n, Q),
         in_specs=[
-            pl.BlockSpec((block_n, S), lambda i, j: (i, j)),
-            pl.BlockSpec((1, K), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, K), lambda i, j: (j, 0)),
+            pl.BlockSpec((None, block_n, S), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((None, 1, K), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, K), lambda i, j: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Np, Q), jnp.float32),
+        out_specs=pl.BlockSpec((None, block_n, 1), lambda i, j: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Q, Np, 1), jnp.float32),
         interpret=interpret,
-    )(slots, means, stds)
-    return out[:N]
+    )(slots_cm, means[:, None, :], stds[:, None, :])
+    return out[:, :N, 0].T

@@ -63,7 +63,8 @@ _LOG2PI = math.log(2.0 * math.pi)
 def _mode_normalize(x, means, stds, logw, g):
     """Shared body of both kernels: Gumbel-argmax mode pick + mode-specific
     normalization.  x (bn, 1); means/stds/logw (1, K); g (bn, K).  Returns
-    (alpha (bn,), onehot (bn, K)); all inputs pre-cast to f32."""
+    (alpha (bn,), comp (bn,) int32, onehot (bn, K)); all inputs pre-cast
+    to f32."""
     z = (x - means) / stds
     logits = -0.5 * z * z - jnp.log(stds) - 0.5 * _LOG2PI + logw + g
     comp = jnp.argmax(logits, axis=1)                   # (bn,)
@@ -72,12 +73,12 @@ def _mode_normalize(x, means, stds, logw, g):
     mu = jnp.sum(onehot * means, axis=1)
     sd = jnp.sum(onehot * stds, axis=1)
     alpha = jnp.clip((x[:, 0] - mu) / (4.0 * sd), -1.0, 1.0)
-    return alpha, onehot
+    return alpha, comp, onehot
 
 
 def _vgm_kernel(x_ref, means_ref, stds_ref, logw_ref, gumbel_ref,
                 alpha_ref, beta_ref):
-    alpha, onehot = _mode_normalize(
+    alpha, _, onehot = _mode_normalize(
         x_ref[...].astype(jnp.float32), means_ref[...].astype(jnp.float32),
         stds_ref[...].astype(jnp.float32), logw_ref[...].astype(jnp.float32),
         gumbel_ref[...].astype(jnp.float32))
@@ -125,12 +126,16 @@ def vgm_encode(x: jnp.ndarray, means: jnp.ndarray, stds: jnp.ndarray,
 
 def _vgm_table_kernel(x_ref, means_ref, stds_ref, logw_ref, gumbel_ref,
                       out_ref):
-    alpha, onehot = _mode_normalize(
+    """One (row block, column) cell.  The ``[alpha, beta_0..]`` slot tile is
+    built with lane selects on the full ``(bn, 1+K)`` tile: lane 0 takes
+    alpha, lane 1+k the one-hot of mode k — no lane-offset stores."""
+    alpha, comp, _ = _mode_normalize(
         x_ref[...].astype(jnp.float32), means_ref[...].astype(jnp.float32),
         stds_ref[...].astype(jnp.float32), logw_ref[...].astype(jnp.float32),
         gumbel_ref[...].astype(jnp.float32))
-    out_ref[:, 0:1] = alpha[:, None]
-    out_ref[:, 1:] = onehot
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    out_ref[...] = jnp.where(lane == 0, alpha[:, None],
+                             (lane == comp[:, None] + 1).astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -147,6 +152,12 @@ def vgm_encode_table(x_cols: jnp.ndarray, means: jnp.ndarray,
 
     Returns slots (N, Q*(1+Kmax)): column q's slot is
     ``[alpha_q, beta_q_0 .. beta_q_{Kmax-1}]`` at offset ``q*(1+Kmax)``.
+
+    Inside, the kernel runs column-major — x ``(Q, N, 1)``, gumbel
+    ``(Q, N, Kmax)``, params ``(Q, 1, Kmax)``, slots ``(Q, N, 1+Kmax)`` —
+    with the column axis squeezed out of every block, so each block's
+    last two dims are (row tile, full width) as Mosaic requires.  The
+    transposes around the call fuse into the caller's jit.
     """
     N, Q = x_cols.shape
     K = means.shape[1]
@@ -156,19 +167,23 @@ def vgm_encode_table(x_cols: jnp.ndarray, means: jnp.ndarray,
         x_cols = jnp.pad(x_cols, ((0, pad_n), (0, 0)))
         gumbel = jnp.pad(gumbel, ((0, pad_n), (0, 0)))
     Np = N + pad_n
+    x_cm = x_cols.T[:, :, None]                              # (Q, Np, 1)
+    g_cm = gumbel.reshape(Np, Q, K).transpose(1, 0, 2)       # (Q, Np, K)
+
+    def param_spec():
+        return pl.BlockSpec((None, 1, K), lambda i, j: (j, 0, 0))
 
     slots = pl.pallas_call(
         _vgm_table_kernel,
         grid=(Np // block_n, Q),
         in_specs=[
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, K), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, K), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, K), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n, K), lambda i, j: (i, j)),
+            pl.BlockSpec((None, block_n, 1), lambda i, j: (j, i, 0)),
+            param_spec(), param_spec(), param_spec(),
+            pl.BlockSpec((None, block_n, K), lambda i, j: (j, i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, S), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Np, Q * S), jnp.float32),
+        out_specs=pl.BlockSpec((None, block_n, S), lambda i, j: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Q, Np, S), jnp.float32),
         interpret=interpret,
-    )(x_cols, means, stds, log_weights, gumbel)
-    return slots[:N]
+    )(x_cm, means[:, None, :], stds[:, None, :], log_weights[:, None, :],
+      g_cm)
+    return slots.transpose(1, 0, 2).reshape(Np, Q * S)[:N]

@@ -28,6 +28,7 @@ from ..configs import ARCH_NAMES, get_config, supported_shapes
 from ..models import Transformer, TrainState, make_train_step, make_serve_step, ShardHints
 from ..models.config import INPUT_SHAPES
 from ..optim import adam
+from .compile_cache import enable_compile_cache
 from .input_specs import input_specs
 from .mesh import make_production_mesh
 from .roofline import analyze_hlo, model_flops_for, roofline_from_stats
@@ -162,6 +163,7 @@ def run_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES)
     ap.add_argument("--shape", choices=list(INPUT_SHAPES))

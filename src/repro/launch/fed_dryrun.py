@@ -33,6 +33,7 @@ from ..configs import ARCH_NAMES, get_config
 from ..models import Transformer, TrainState, make_train_step, ShardHints
 from ..models.config import INPUT_SHAPES
 from ..optim import adam
+from .compile_cache import enable_compile_cache
 from .dryrun import _adam_for
 from .input_specs import train_input_specs
 from .mesh import make_production_mesh
@@ -270,6 +271,7 @@ def run_one(arch: str, multi_pod: bool, agg_dtype: str = "f32",
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES + ["ctgan-paper"])
     ap.add_argument("--all", action="store_true")

@@ -6,13 +6,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # axis_types only exists on jax >= 0.5 (sharding-in-types); older
-    # versions default every axis to Auto anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

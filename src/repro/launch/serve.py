@@ -23,6 +23,7 @@ import numpy as np
 
 from ..configs import ARCH_NAMES, get_config, get_smoke_config, supported_shapes
 from ..models import Transformer, make_serve_step
+from .compile_cache import enable_compile_cache
 
 
 def prefill_and_decode(cfg, *, batch, prompt_len, gen_tokens, seed=0,
@@ -147,6 +148,7 @@ def run_tabular_server(*, requests: int = 16,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="smollm-135m")
     ap.add_argument("--smoke", action="store_true")

@@ -32,6 +32,7 @@ from ..data.tokens import (TokenDatasetSpec, client_token_streams,
 from ..kernels import ops as kernel_ops
 from ..models import Transformer, TrainState, make_train_step
 from ..optim import adam, cosine_schedule
+from .compile_cache import enable_compile_cache
 
 
 def _batch_dict(cfg, tokens: np.ndarray, key) -> dict:
@@ -149,6 +150,7 @@ def run_federated(cfg, *, clients, rounds, local_steps, batch, seq, lr,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="smollm-135m")
     ap.add_argument("--smoke", action="store_true")

@@ -11,7 +11,7 @@ from repro.core.architectures import run_federated
 from repro.fed import (FederatedProgram, SCENARIOS, fused_weighted_merge,
                        partition, resolve_weights, setup_federation,
                        shard_map_global_round)
-from repro.gan.ctgan import CTGANConfig
+from repro.gan.ctgan import CTGANConfig, generator_forward, is_pre_bn_bias
 from repro.kernels import ops, ref
 from repro.kernels.weighted_agg import weighted_agg
 from repro.tabular import make_dataset, partition_iid
@@ -213,10 +213,37 @@ class TestShardMapPath:
         assert m_sm["d_loss"].shape == m_vm["d_loss"].shape
         np.testing.assert_allclose(np.asarray(m_sm["d_loss"]),
                                    np.asarray(m_vm["d_loss"]), rtol=1e-5)
-        for got, exp in zip(jax.tree.leaves(st_sm.g_params),
-                            jax.tree.leaves(st_vm.g_params)):
-            np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
-                                       rtol=1e-5, atol=1e-6)
+        # Every generator leaf agrees at rtol 1e-5 except the pre-batch-norm
+        # biases ``res*/fc/b``.  The batch norm after each subtracts the
+        # batch mean, so their exact gradient is zero and what either
+        # rendering computes for it is rounding noise (~1e-9; the vmapped
+        # client reduces in another order than the un-vmapped shard_map
+        # slice).  Adam's first steps normalise that noise to updates of up
+        # to lr each, so those leaves are held to 2 * lr per local step,
+        # and the generator output, in which the bias cancels, is held to
+        # the tight bound instead.
+        # The discriminator has no such leaf: all of it is held tight.
+        pre_bn_bias_bound = 2 * CFG.lr          # one local step
+        for which in ("g_params", "d_params"):
+            flat_sm = jax.tree_util.tree_flatten_with_path(
+                getattr(st_sm, which))[0]
+            flat_vm = jax.tree.leaves(getattr(st_vm, which))
+            for (path, got), exp in zip(flat_sm, flat_vm):
+                got, exp = np.asarray(got), np.asarray(exp)
+                if which == "g_params" and is_pre_bn_bias(path):
+                    assert np.max(np.abs(got - exp)) <= pre_bn_bias_bound
+                else:
+                    np.testing.assert_allclose(got, exp, rtol=1e-5,
+                                               atol=1e-6)
+        z = jax.random.normal(jax.random.PRNGKey(1),
+                              (CFG.batch_size, CFG.z_dim))
+        cond = jnp.zeros((CFG.batch_size, fe1.enc.cond_dim))
+        first = lambda t: jax.tree.map(lambda x: x[0], t)
+        out_sm, out_vm = (generator_forward(first(st.g_params), z, cond,
+                                            len(CFG.gen_hidden))
+                          for st in (st_sm, st_vm))
+        np.testing.assert_allclose(np.asarray(out_sm), np.asarray(out_vm),
+                                   rtol=1e-5, atol=1e-5)
 
 
 class TestScenarios:

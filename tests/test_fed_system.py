@@ -78,7 +78,6 @@ class TestAggregation:
     def test_psum_weighted_matches_host(self, key):
         """SPMD weighted merge over the client axis == host-side average."""
         from jax.sharding import PartitionSpec as P
-        from repro.core.fedavg import _CHECK_KW, _shard_map
         from repro.launch.mesh import _make_mesh
         n = len(jax.devices())
         mesh = _make_mesh((n,), ("c",))
@@ -88,8 +87,8 @@ class TestAggregation:
         def merge(v, wi):
             return psum_weighted(v[0], wi[0], "c")[None]
 
-        out = _shard_map(merge, mesh=mesh, in_specs=(P("c"), P("c")),
-                         out_specs=P("c"), **{_CHECK_KW: False})(vals, w)
+        out = jax.shard_map(merge, mesh=mesh, in_specs=(P("c"), P("c")),
+                            out_specs=P("c"), check_vma=False)(vals, w)
         expect = weighted_average(vals, w)
         np.testing.assert_allclose(np.asarray(out[0]), np.asarray(expect),
                                    rtol=1e-5, atol=1e-6)

@@ -58,13 +58,22 @@ def _paths(spans, logits, akey, tau, hard):
     return loop, fused_ref, fused_kernel
 
 
-def _check_value_parity(spans, dim, batch, seed, tau=0.2, hard=False):
+def _check_value_parity(spans, dim, batch, seed, tau=0.2, hard=False,
+                        kernel_rtol=None):
+    """Loop == fused ref bit for bit; the interpret-mode kernel too, or
+    within ``kernel_rtol`` where a caller states why."""
     key = jax.random.PRNGKey(seed)
     kl, ka = jax.random.split(key)
     logits = jax.random.normal(kl, (batch, dim), jnp.float32) * 3.0
     loop, fused_ref, fused_kernel = _paths(spans, logits, ka, tau, hard)
     np.testing.assert_array_equal(np.asarray(loop), np.asarray(fused_ref))
-    np.testing.assert_array_equal(np.asarray(loop), np.asarray(fused_kernel))
+    if kernel_rtol is None:
+        np.testing.assert_array_equal(np.asarray(loop),
+                                      np.asarray(fused_kernel))
+    else:
+        np.testing.assert_allclose(np.asarray(fused_kernel),
+                                   np.asarray(loop), rtol=kernel_rtol,
+                                   atol=0)
     return logits, ka, np.asarray(loop)
 
 
@@ -228,9 +237,16 @@ if HAS_HYPOTHESIS:
         @given(hst.integers(0, 10_000), hst.integers(1, 257),
                hst.booleans())
         def test_values_bit_exact(self, seed, batch, hard):
+            # Loop vs fused ref stays bit-exact.  The interpret-mode kernel
+            # is held to 1e-5 relative: XLA's CPU backend emits ``exp``
+            # differently inside the jitted ref's fusion than inside the
+            # interpreted kernel body, up to 4 ulp on identical inputs
+            # (seed=5042, batch=1: z equal bit for bit, exp(z - m) 4.8e-7
+            # apart near 1.0, hence 2.2e-6 relative in the softmax).
             rng = np.random.default_rng(seed)
             spans, dim = _random_layout(rng)
-            _check_value_parity(spans, dim, batch, seed, hard=hard)
+            _check_value_parity(spans, dim, batch, seed, hard=hard,
+                                kernel_rtol=1e-5)
 
         @settings(max_examples=8, deadline=None)
         @given(hst.integers(0, 10_000), hst.integers(1, 257),
