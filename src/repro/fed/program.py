@@ -58,6 +58,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.fedavg import fedprox_wrap, sample_participation
 from ..core.weighting import (quantity_only_weights, uniform_weights,
                               weights_from_divergence)
@@ -74,6 +75,34 @@ from .merge import (flatten_stacked, fused_weighted_merge, replicate,
                     unflatten_merged)
 
 WEIGHTINGS = ("fedtgan", "uniform", "quantity")
+
+
+class _Entry:
+    """A jitted entry point of :class:`FederatedProgram` that counts what
+    the host asks of it: each call adds one to ``fed.dispatches`` and its
+    global rounds to ``fed.rounds`` and runs under the host span
+    ``repro.fed.<name>``; each jit trace of the body adds one to
+    ``jit_traces`` under ``fed.<name>`` (the body runs only when JAX
+    traces it, so the count shows which entry point retraced).
+    ``lower`` is the jitted function's own."""
+
+    def __init__(self, name: str, fn, scanned: bool):
+        def traced(*args):
+            obs.counter("jit_traces")["fed." + name] += 1
+            return fn(*args)
+
+        traced.__name__ = fn.__name__
+        self._jit = jax.jit(traced)
+        self.lower = self._jit.lower
+        self._span = "repro.fed." + name
+        self._scanned = scanned
+
+    def __call__(self, *args):
+        fed = obs.counter("fed")
+        fed["dispatches"] += 1
+        fed["rounds"] += args[4].shape[0] if self._scanned else 1
+        with obs.span(self._span):
+            return self._jit(*args)
 
 
 def _gan_lens(state: GANState):
@@ -174,15 +203,19 @@ class FederatedProgram:
                              "wraps or replaces the step_fn)")
         self.engine = engine
         self._merge_kw = dict(use_pallas=use_pallas, interpret=interpret)
-        self.round = jax.jit(self.global_round)
-        self.run = jax.jit(self._run_impl)
-        self.round_faulted = jax.jit(self.faulted_global_round)
-        self.run_faulted = jax.jit(self._run_faulted_impl)
-        self.round_traced = jax.jit(self.traced_global_round)
-        self.run_traced = jax.jit(self._run_traced_impl)
+        self.round = _Entry("round", self.global_round, False)
+        self.run = _Entry("run", self._run_impl, True)
+        self.round_faulted = _Entry("round_faulted",
+                                    self.faulted_global_round, False)
+        self.run_faulted = _Entry("run_faulted", self._run_faulted_impl,
+                                  True)
+        self.round_traced = _Entry("round_traced", self.traced_global_round,
+                                   False)
+        self.run_traced = _Entry("run_traced", self._run_traced_impl, True)
 
     # -- the one-program round -------------------------------------------
 
+    @jax.named_scope("merge")
     def merge_states(self, states: GANState, w: jnp.ndarray) -> GANState:
         """Federator merge + redistribution: G and D parameters flattened
         into ONE ``weighted_agg`` dispatch (one per tier under
@@ -259,16 +292,17 @@ class FederatedProgram:
         ``(states, metrics, flat_updates)``."""
         P = w.shape[0]
         states, metrics = self._clients(states, tables, key)
-        tree = {"g": states.g_params, "d": states.d_params}
-        flat = flatten_stacked(tree)
-        if self.n_edges is None:
-            merged = ops.weighted_average_flat(flat, w, **self._merge_kw)
-        else:
-            merged = tiered_weighted_merge_flat(flat, w, self.n_edges,
-                                                **self._merge_kw)
-        out = unflatten_merged(merged, tree)
-        states = states._replace(g_params=replicate(out["g"], P),
-                                 d_params=replicate(out["d"], P))
+        with jax.named_scope("merge"):
+            tree = {"g": states.g_params, "d": states.d_params}
+            flat = flatten_stacked(tree)
+            if self.n_edges is None:
+                merged = ops.weighted_average_flat(flat, w, **self._merge_kw)
+            else:
+                merged = tiered_weighted_merge_flat(flat, w, self.n_edges,
+                                                    **self._merge_kw)
+            out = unflatten_merged(merged, tree)
+            states = states._replace(g_params=replicate(out["g"], P),
+                                     d_params=replicate(out["d"], P))
         return states, metrics, flat
 
     def traced_global_round(self, states: GANState, tables: SamplerTables,
@@ -328,35 +362,37 @@ class FederatedProgram:
             kp, key = jax.random.split(key)
             participate = participate & sample_participation(
                 w, kp, self.participation)
-        prev_flat = flatten_stacked({"g": states.g_params,
-                                     "d": states.d_params})
+        with jax.named_scope("merge"):
+            prev_flat = flatten_stacked({"g": states.g_params,
+                                         "d": states.d_params})
         states, metrics = self._clients(states, tables, key)
-        tree = {"g": states.g_params, "d": states.d_params}
-        flat = apply_faults(flatten_stacked(tree), prev_flat,
-                            fault.nan_mask, fault.scale)
-        norm_mult = (self.guard.norm_mult if self.guard is not None
-                     and self.guard.norm_mult > 0 else None)
-        diag = update_diagnostics(
-            flat, prev_flat, participate,
-            **({} if norm_mult is None else {"norm_mult": norm_mult}))
-        ok = guard_ok(self.guard, diag, participate)
-        w_eff = w * ok
-        wsum = jnp.sum(w_eff)
-        flat_safe = jnp.where(ok[:, None], flat, 0.0)
-        if self.n_edges is None:
-            merged = ops.weighted_average_flat(flat_safe, w_eff,
-                                               **self._merge_kw)
-        else:
-            # same mask + renormalize math, folded tier-wise: a fully
-            # masked edge carries tier weight 0 and exact-zero values,
-            # so in-kernel renormalization still happens per tier.
-            merged = tiered_weighted_merge_flat(flat_safe, w_eff,
-                                                self.n_edges,
-                                                **self._merge_kw)
-        merged = jnp.where(wsum > 0, merged, prev_flat[0])
-        out = unflatten_merged(merged, tree)
-        states = states._replace(g_params=replicate(out["g"], P),
-                                 d_params=replicate(out["d"], P))
+        with jax.named_scope("merge"):
+            tree = {"g": states.g_params, "d": states.d_params}
+            flat = apply_faults(flatten_stacked(tree), prev_flat,
+                                fault.nan_mask, fault.scale)
+            norm_mult = (self.guard.norm_mult if self.guard is not None
+                         and self.guard.norm_mult > 0 else None)
+            diag = update_diagnostics(
+                flat, prev_flat, participate,
+                **({} if norm_mult is None else {"norm_mult": norm_mult}))
+            ok = guard_ok(self.guard, diag, participate)
+            w_eff = w * ok
+            wsum = jnp.sum(w_eff)
+            flat_safe = jnp.where(ok[:, None], flat, 0.0)
+            if self.n_edges is None:
+                merged = ops.weighted_average_flat(flat_safe, w_eff,
+                                                   **self._merge_kw)
+            else:
+                # same mask + renormalize math, folded tier-wise: a fully
+                # masked edge carries tier weight 0 and exact-zero values,
+                # so in-kernel renormalization still happens per tier.
+                merged = tiered_weighted_merge_flat(flat_safe, w_eff,
+                                                    self.n_edges,
+                                                    **self._merge_kw)
+            merged = jnp.where(wsum > 0, merged, prev_flat[0])
+            out = unflatten_merged(merged, tree)
+            states = states._replace(g_params=replicate(out["g"], P),
+                                     d_params=replicate(out["d"], P))
         metrics = dict(metrics, client_ok=ok,
                        client_suspect=participate & diag["suspect"],
                        update_norm=diag["norm"],

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.encoding import (FederatedInit, client_vgm_dicts,
                              compute_client_stats, federated_encoder_init)
 from ..core.weighting import build_divergence_matrix
@@ -122,34 +123,46 @@ def setup_federation(client_data: list[np.ndarray], schema: list[ColumnSpec],
     key = jax.random.PRNGKey(seed)
     k_stats, k_init, k_w, k_model, k_enc = jax.random.split(key, 5)
 
-    stats = [compute_client_stats(d, schema, jax.random.fold_in(k_stats, i))
-             for i, d in enumerate(client_data)]
-    init = federated_encoder_init(stats, schema, k_init)
+    # Host spans (while obs is on).  vgm_fit, encoder_init and states
+    # leave their results on the device, so they time the host's work and
+    # enqueueing; divergence and encode read results back to the host, so
+    # they also wait for the device work queued before them.
+    with obs.span("repro.setup.vgm_fit"):
+        stats = [compute_client_stats(d, schema,
+                                      jax.random.fold_in(k_stats, i))
+                 for i, d in enumerate(client_data)]
+    with obs.span("repro.setup.encoder_init"):
+        init = federated_encoder_init(stats, schema, k_init)
     n_rows = jnp.asarray(init.n_rows, jnp.float32)
 
-    if weighting == "fedtgan":
-        S = build_divergence_matrix(schema, init.client_cat_freqs,
-                                    client_vgm_dicts(stats), init.encoders,
-                                    init.global_cat_freqs, k_w)
-    else:
-        # placeholder with the right client axis; dead code in-program
-        S = jnp.zeros((P, len(schema)), jnp.float32)
-    # jitted so the host copy folds EXACTLY like the in-program recompute:
-    # the eager trace can round the Fig.4 softmax a final ulp differently,
-    # and GAN rounds amplify that into host-vs-program parity noise
-    w = jax.jit(resolve_weights, static_argnums=0)(weighting, S, n_rows)
+    with obs.span("repro.setup.divergence"):
+        if weighting == "fedtgan":
+            S = build_divergence_matrix(schema, init.client_cat_freqs,
+                                        client_vgm_dicts(stats),
+                                        init.encoders, init.global_cat_freqs,
+                                        k_w)
+        else:
+            # placeholder with the right client axis; dead code in-program
+            S = jnp.zeros((P, len(schema)), jnp.float32)
+        # jitted so the host copy folds EXACTLY like the in-program
+        # recompute: the eager trace can round the Fig.4 softmax a final
+        # ulp differently, and GAN rounds amplify that into
+        # host-vs-program parity noise
+        w = jax.jit(resolve_weights, static_argnums=0)(weighting, S, n_rows)
 
     enc = init.encoders
     # stack the per-client sampler tables right away so only ONE device
     # copy (the stacked, vmap-ready one) stays resident for the run
-    tables = stack_sampler_tables([DeviceSampler(
-        np.asarray(enc.encode(d, jax.random.fold_in(k_enc, i))), enc)
-        for i, d in enumerate(client_data)])
+    with obs.span("repro.setup.encode"):
+        tables = stack_sampler_tables([DeviceSampler(
+            np.asarray(enc.encode(d, jax.random.fold_in(k_enc, i))), enc)
+            for i, d in enumerate(client_data)])
     # Federator initializes ONE model and distributes it (identical start).
-    state0 = init_gan_state(k_model, cfg, enc.cond_dim, enc.encoded_dim)
-    states = [state0._replace(rng=jax.random.fold_in(state0.rng, i))
-              for i in range(P)]
-    states = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+    with obs.span("repro.setup.states"):
+        state0 = init_gan_state(k_model, cfg, enc.cond_dim, enc.encoded_dim)
+        states = [state0._replace(rng=jax.random.fold_in(state0.rng, i))
+                  for i in range(P)]
+        states = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
     return Federation(init, enc, tuple(enc.spans()),
                       tuple(enc.condition_spans()), tables, states,
                       S, n_rows, w, weighting, client_stats=stats)

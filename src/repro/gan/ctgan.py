@@ -171,6 +171,7 @@ def discriminator_forward(params: dict, x: jnp.ndarray, key: jax.Array,
 # losses
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("gradient_penalty")
 def gradient_penalty(d_params: dict, real: jnp.ndarray, fake: jnp.ndarray,
                      key: jax.Array, cfg: CTGANConfig) -> jnp.ndarray:
     """WGAN-GP with pac-aware interpolation (one epsilon per pack)."""

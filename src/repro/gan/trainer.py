@@ -83,12 +83,17 @@ def make_train_steps(cfg: CTGANConfig, spans: Sequence[SpanInfo],
     def step(state: GANState, batch):
         cond, mask, real = batch
         key, kd, kg = jax.random.split(state.rng, 3)
-        (dl, (wgan, gp)), d_grads = jax.value_and_grad(d_loss_fn, has_aux=True)(
-            state.d_params, state.g_params, cond, real, kd)
-        d_params, d_opt = opt.update(d_grads, state.d_opt, state.d_params)
-        (gl, ce), g_grads = jax.value_and_grad(g_loss_fn, has_aux=True)(
-            state.g_params, d_params, cond, mask, kg)
-        g_params, g_opt = opt.update(g_grads, state.g_opt, state.g_params)
+        with jax.named_scope("d_step"):
+            (dl, (wgan, gp)), d_grads = jax.value_and_grad(
+                d_loss_fn, has_aux=True)(state.d_params, state.g_params,
+                                         cond, real, kd)
+            d_params, d_opt = opt.update(d_grads, state.d_opt,
+                                         state.d_params)
+        with jax.named_scope("g_step"):
+            (gl, ce), g_grads = jax.value_and_grad(g_loss_fn, has_aux=True)(
+                state.g_params, d_params, cond, mask, kg)
+            g_params, g_opt = opt.update(g_grads, state.g_opt,
+                                         state.g_params)
         new = GANState(g_params, d_params, g_opt, d_opt, state.step + 1, key)
         return new, {"d_loss": dl, "g_loss": gl, "wgan": wgan, "gp": gp, "ce": ce}
 

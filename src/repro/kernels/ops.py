@@ -14,6 +14,7 @@ import contextlib
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import ref
 from .flash_attention import flash_attention as _flash
 from .mlstm_chunk import mlstm_chunk as _mlstm_chunk
@@ -40,15 +41,20 @@ _weighted_agg_ref = jax.jit(ref.weighted_agg_ref)
 # kernel/oracle call merges every edge aggregator's stack at once.
 _weighted_agg_edges_ref = jax.jit(jax.vmap(ref.weighted_agg_ref))
 
-# Host-level kernel dispatch counter (per wrapper call); benchmarks use it
-# to prove the fused encode path issues ONE dispatch where the per-column
-# loop issues Q_cont.  Reset with ``DISPATCH_COUNTS.clear()``.
-DISPATCH_COUNTS: collections.Counter = collections.Counter()
+# Backend resolutions per wrapper call, keyed ``<stage>`` (Pallas route)
+# or ``<stage>_ref`` (jnp route).  A wrapper called eagerly resolves once
+# per call, which is once per device dispatch; under ``jit`` the wrapper
+# runs only while JAX traces, so the count is per trace, however often
+# the compiled program then runs.  Benchmarks use it to prove the fused
+# encode path issues ONE kernel where the per-column loop issues Q_cont.
+# Reset with ``DISPATCH_COUNTS.clear()`` or ``obs.reset()``.
+DISPATCH_COUNTS: collections.Counter = obs.counter("kernel_routes")
 
-# Pallas-route calls that ran in the interpreter (per stage).  On the chip
-# this must stay empty: a kernel quietly interpreted there would run the
-# stage on the host while the run still reports the Pallas route.
-INTERPRET_COUNTS: collections.Counter = collections.Counter()
+# Pallas-route calls that ran in the interpreter (per stage, counted like
+# DISPATCH_COUNTS).  On the chip this must stay empty: a kernel quietly
+# interpreted there would run the stage on the host while the run still
+# reports the Pallas route.
+INTERPRET_COUNTS: collections.Counter = obs.counter("kernel_interpret")
 
 # Row-tile cap for the TPU kernels (VMEM-sized VPU tiles).
 _MAX_ROW_TILE = 1024
@@ -217,10 +223,11 @@ def segment_activations(logits, spans, key, tau, hard=False, *,
             u = jax.random.uniform(keys[i], (n, s.width), jnp.float32)
             us.append(jnp.pad(u, ((0, 0), (0, layout.wmax - s.width)),
                               constant_values=0.5))
-    packed_u = jnp.concatenate(us, axis=1)
-    packed_x = jnp.where(layout.pack_pad[None, :], -jnp.inf,
-                         jnp.take(logits.astype(jnp.float32),
-                                  layout.pack_src, axis=1))
+    with jax.named_scope("kernel_layout"):
+        packed_u = jnp.concatenate(us, axis=1)
+        packed_x = jnp.where(layout.pack_pad[None, :], -jnp.inf,
+                             jnp.take(logits.astype(jnp.float32),
+                                      layout.pack_src, axis=1))
     tau, hard = float(tau), bool(hard)
     interp = _route("segment_activations", use_pallas, interpret)
     if interp is None:
@@ -230,7 +237,8 @@ def segment_activations(logits, spans, key, tau, hard=False, *,
         out = segment_activations_packed(packed_x, packed_u, layout.kinds,
                                          tau, hard, True, interp,
                                          _block_rows(n, interp, block_n))
-    return jnp.take(out, layout.unpack_src, axis=1)
+    with jax.named_scope("kernel_layout"):
+        return jnp.take(out, layout.unpack_src, axis=1)
 
 
 def mlstm_chunk(q, k, v, log_f, log_i, *, use_pallas=True, interpret=None,

@@ -153,14 +153,17 @@ def segment_activations(packed_x: jnp.ndarray, packed_u: jnp.ndarray,
     N = packed_x.shape[0]
     S, W = kinds.shape
     pad_n = (-N) % block_n
-    if pad_n:
-        packed_x = jnp.pad(packed_x, ((0, pad_n), (0, 0)))
-        packed_u = jnp.pad(packed_u, ((0, pad_n), (0, 0)),
-                           constant_values=0.5)
     Np = N + pad_n
 
     def span_major(a):                                    # (S, Np, W)
         return a.reshape(Np, S, W).transpose(1, 0, 2)
+
+    with jax.named_scope("kernel_layout"):
+        if pad_n:
+            packed_x = jnp.pad(packed_x, ((0, pad_n), (0, 0)))
+            packed_u = jnp.pad(packed_u, ((0, pad_n), (0, 0)),
+                               constant_values=0.5)
+        x_sm, u_sm = span_major(packed_x), span_major(packed_u)
 
     def rows_block():
         return pl.BlockSpec((None, block_n, W), lambda i, j: (j, i, 0))
@@ -173,8 +176,9 @@ def segment_activations(packed_x: jnp.ndarray, packed_u: jnp.ndarray,
         out_specs=rows_block(),
         out_shape=jax.ShapeDtypeStruct((S, Np, W), jnp.float32),
         interpret=interpret,
-    )(span_major(packed_x), span_major(packed_u), kinds[:, None, :])
-    return out.transpose(1, 0, 2).reshape(Np, S * W)[:N]
+    )(x_sm, u_sm, kinds[:, None, :])
+    with jax.named_scope("kernel_layout"):
+        return out.transpose(1, 0, 2).reshape(Np, S * W)[:N]
 
 
 def _packed_primal(packed_x, packed_u, kinds, tau, hard, use_pallas,

@@ -89,10 +89,11 @@ def vgm_decode_table(slots: jnp.ndarray, means: jnp.ndarray,
     Q, K = means.shape
     S = 1 + K
     pad_n = (-N) % block_n
-    if pad_n:
-        slots = jnp.pad(slots, ((0, pad_n), (0, 0)))
     Np = N + pad_n
-    slots_cm = slots.reshape(Np, Q, S).transpose(1, 0, 2)   # (Q, Np, S)
+    with jax.named_scope("kernel_layout"):
+        if pad_n:
+            slots = jnp.pad(slots, ((0, pad_n), (0, 0)))
+        slots_cm = slots.reshape(Np, Q, S).transpose(1, 0, 2)  # (Q, Np, S)
 
     out = pl.pallas_call(
         _vgm_decode_kernel,
@@ -106,4 +107,5 @@ def vgm_decode_table(slots: jnp.ndarray, means: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((Q, Np, 1), jnp.float32),
         interpret=interpret,
     )(slots_cm, means[:, None, :], stds[:, None, :])
-    return out[:, :N, 0].T
+    with jax.named_scope("kernel_layout"):
+        return out[:, :N, 0].T

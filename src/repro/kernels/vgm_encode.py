@@ -163,12 +163,13 @@ def vgm_encode_table(x_cols: jnp.ndarray, means: jnp.ndarray,
     K = means.shape[1]
     S = 1 + K
     pad_n = (-N) % block_n
-    if pad_n:
-        x_cols = jnp.pad(x_cols, ((0, pad_n), (0, 0)))
-        gumbel = jnp.pad(gumbel, ((0, pad_n), (0, 0)))
     Np = N + pad_n
-    x_cm = x_cols.T[:, :, None]                              # (Q, Np, 1)
-    g_cm = gumbel.reshape(Np, Q, K).transpose(1, 0, 2)       # (Q, Np, K)
+    with jax.named_scope("kernel_layout"):
+        if pad_n:
+            x_cols = jnp.pad(x_cols, ((0, pad_n), (0, 0)))
+            gumbel = jnp.pad(gumbel, ((0, pad_n), (0, 0)))
+        x_cm = x_cols.T[:, :, None]                          # (Q, Np, 1)
+        g_cm = gumbel.reshape(Np, Q, K).transpose(1, 0, 2)   # (Q, Np, K)
 
     def param_spec():
         return pl.BlockSpec((None, 1, K), lambda i, j: (j, 0, 0))
@@ -186,4 +187,5 @@ def vgm_encode_table(x_cols: jnp.ndarray, means: jnp.ndarray,
         interpret=interpret,
     )(x_cm, means[:, None, :], stds[:, None, :], log_weights[:, None, :],
       g_cm)
-    return slots.transpose(1, 0, 2).reshape(Np, Q * S)[:N]
+    with jax.named_scope("kernel_layout"):
+        return slots.transpose(1, 0, 2).reshape(Np, Q * S)[:N]

@@ -110,9 +110,10 @@ class RoundEngine:
         layer uses to hand FedProx-wrapped steps the round's global
         params (see :func:`repro.core.fedavg.fedprox_wrap`)."""
         E = self.local_steps
-        big = draw_batch(tables, key, E * self.batch, self.cond_dim)
-        batches = jax.tree.map(
-            lambda a: a.reshape(E, self.batch, *a.shape[1:]), big)
+        with jax.named_scope("draw"):
+            big = draw_batch(tables, key, E * self.batch, self.cond_dim)
+            batches = jax.tree.map(
+                lambda a: a.reshape(E, self.batch, *a.shape[1:]), big)
 
         def body(st, b):
             return self.step_fn(st, b if aux is None else (b, aux))

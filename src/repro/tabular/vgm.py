@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
+
 _LOG2PI = float(np.log(2.0 * np.pi))
 
 
@@ -65,6 +67,7 @@ def fit_vgm(x: jnp.ndarray, key: jax.Array, *, max_modes: int = 10,
     Initialization: quantile-spread means (deterministic given data) plus a
     tiny key-derived jitter to break ties on constant data.
     """
+    obs.counter("jit_traces")["vgm.fit"] += 1
     x = x.astype(jnp.float32)
     n = x.shape[0]
     data_std = jnp.maximum(jnp.std(x), 1e-6)

@@ -114,3 +114,63 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 def test_row_tile_divides_padded_rows(n, tile):
     assert ops.row_tile(n) == tile
     assert tile % 8 == 0
+
+
+SCOPES = {"draw", "d_step", "gradient_penalty", "g_step", "merge",
+          "kernel_layout"}
+
+
+def _scopes(op_name):
+    """The scope names in an op's name stack, transformation wrappers
+    (``transpose(jvp(d_step))``, ``vmap(draw)``) taken off."""
+    out = []
+    for part in op_name.split("/"):
+        while "(" in part and not part.startswith("jit(") \
+                and part.endswith(")"):
+            part = part[part.index("(") + 1:-1]
+        if part in SCOPES:
+            out.append(part)
+    return out
+
+
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
+def test_round_phases_named_in_compiled_program(request, monkeypatch,
+                                                target):
+    """``FederatedProgram.run`` at tiny widths, 2 rounds: every phase scope
+    reaches the compiled program's op metadata, and on the chip's
+    compile the Mosaic calls sit in their phases."""
+    import re
+
+    import numpy as np
+
+    from repro.fed import FederatedProgram, setup_federation
+    from repro.gan.ctgan import CTGANConfig
+    from repro.tabular import ColumnSpec
+    rng = np.random.default_rng(0)
+    schema = [ColumnSpec("x", "continuous", max_modes=2),
+              ColumnSpec("c", "categorical")]
+    parts = [np.stack([rng.normal(size=48), rng.integers(0, 3, 48)], 1)
+             for _ in range(2)]
+    cfg = CTGANConfig(batch_size=8, gen_hidden=(16,), disc_hidden=(16,),
+                      pac=2, z_dim=4)
+    fe = setup_federation(parts, schema, cfg, seed=0)
+    keys = FederatedProgram.fold_round_keys(jax.random.PRNGKey(2), 0, 2)
+    args = (fe.states, fe.tables, fe.S, fe.n_rows, keys)
+    if target == "v5e":
+        sharding = request.getfixturevalue("one_chip")
+        args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), args)
+        monkeypatch.setattr(ops, "_ON_TPU", True)   # take the kernel routes
+    prog = FederatedProgram(cfg, fe.spans, fe.cond_spans, batch=8,
+                            local_steps=1)
+    hlo = prog.run.lower(*args).compile().as_text()
+    instr = re.findall(r'%([^\s=]+) = [^\n]*?op_name="([^"]*)"', hlo)
+    found = {s for _, stack in instr for s in _scopes(stack)}
+    assert found == SCOPES
+    calls = {name: _scopes(stack)[-1] for name, stack in instr
+             if name.startswith(("weighted_agg", "jvp_jit_segment_act"))}
+    if target == "v5e":
+        assert sorted(calls.values()) == ["d_step", "g_step", "merge"]
+        assert all(calls[n] == "merge" for n in calls
+                   if n.startswith("weighted_agg"))
+        assert hlo.count("tpu_custom_call") >= 3
